@@ -34,7 +34,7 @@ def main() -> None:
                                      use_kernels=args.use_kernels,
                                      ce_chunk=args.ce_chunk)
     donate = {"train": (0, 1), "decode": (1,), "prefill": ()}[shape.kind]
-    with mesh:
+    with jax.set_mesh(mesh):
         compiled = jax.jit(step_fn, in_shardings=insh,
                            donate_argnums=donate).lower(*sargs).compile()
     text = compiled.as_text()

@@ -818,6 +818,8 @@ def main() -> None:
                     help="--gate: fractional regression tolerance "
                          "(default 0.5 = 50%%)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     names = args.only.split(",") if args.only else list(BENCHES)
     print("name,us_per_call,derived")
     for name in names:

@@ -18,6 +18,9 @@
 set -euo pipefail
 cd "$(dirname "$0")/.."
 export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
+# the suite runs on the CPU: its concurrent pytest batches (and the test
+# children they start) must never contend for an attached accelerator
+export JAX_PLATFORMS=cpu
 
 args=()
 for a in "$@"; do

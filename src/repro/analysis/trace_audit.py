@@ -88,6 +88,14 @@ def iter_eqns(jaxpr):
             yield from iter_eqns(sub)
 
 
+# every primitive that calls back into the host Python process mid-program
+# (jax 0.9 names; ``jax.debug.print`` lowers to ``debug_print``)
+HOST_CALLBACK_PRIMITIVES = frozenset({
+    "debug_print", "debug_callback", "pure_callback", "io_callback",
+    "callback", "outside_call",
+})
+
+
 def audit_jaxpr(fn: Callable, args: tuple, *, name: str, path: str
                 ) -> List[Finding]:
     """Callback + f64 audit on the traced jaxpr of ``fn(*args)``."""
@@ -97,7 +105,7 @@ def audit_jaxpr(fn: Callable, args: tuple, *, name: str, path: str
     callbacks = set()
     for eqn in iter_eqns(closed.jaxpr):
         pname = eqn.primitive.name
-        if "callback" in pname or "outside_call" in pname:
+        if pname in HOST_CALLBACK_PRIMITIVES:
             callbacks.add(pname)
         for v in eqn.outvars:
             dt = str(getattr(v.aval, "dtype", ""))

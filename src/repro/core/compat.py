@@ -1,40 +1,20 @@
-"""Version-compat shims for jax APIs that moved between releases.
+"""The repo's single import point for ``shard_map`` and the distributed
+runtime.
 
-``shard_map`` graduated from ``jax.experimental.shard_map`` (jax 0.4.x) to a
-top-level ``jax.shard_map`` export, and its replication-check kwarg was
-renamed ``check_rep`` -> ``check_vma`` along the way. Importing through this
-module keeps every call site working on either side of the move — callers
-pass whichever kwarg name they like and it is translated to what the
-installed jax accepts.
+``shard_map`` is re-exported from jax (0.9: ``jax.shard_map``, with the
+``check_vma`` kwarg); lint rule ``shard-map-import`` keeps every call site
+importing it from here, so a future move of the API is one edit.
 
 ``distributed_initialize`` is the one place the repo touches
-``jax.distributed``: it drops ``None`` arguments (jax's auto-detection
-kwargs changed defaults across 0.4.x) and is idempotent, so a launcher that
-already initialized the runtime (SLURM plugin, test harness) composes with
-library code that defensively calls it again.
+``jax.distributed``. It is idempotent, so a launcher that already
+initialized the runtime (SLURM plugin, test harness) composes with library
+code that defensively calls it again.
 """
 from __future__ import annotations
 
-import inspect
 from typing import Optional
 
-try:                                   # jax >= 0.6: top-level export
-    from jax import shard_map as _shard_map  # type: ignore[attr-defined]
-except ImportError:                    # jax 0.4.x / 0.5.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_SHARD_MAP_PARAMS = frozenset(inspect.signature(_shard_map).parameters)
-
-
-def shard_map(f, **kwargs):
-    """`shard_map(f, mesh=..., in_specs=..., out_specs=..., ...)` with the
-    `check_vma` / `check_rep` kwarg translated for the installed jax."""
-    if "check_vma" in kwargs and "check_vma" not in _SHARD_MAP_PARAMS:
-        kwargs["check_rep"] = kwargs.pop("check_vma")
-    elif "check_rep" in kwargs and "check_rep" not in _SHARD_MAP_PARAMS:
-        kwargs["check_vma"] = kwargs.pop("check_rep")
-    return _shard_map(f, **kwargs)
-
+from jax import shard_map  # noqa: F401  (re-exported; see module docstring)
 
 _DIST_INITIALIZED = False
 
@@ -44,20 +24,19 @@ def distributed_initialize(coordinator_address: Optional[str] = None,
                            process_id: Optional[int] = None) -> None:
     """Idempotent ``jax.distributed.initialize``.
 
-    ``None`` arguments are dropped so jax's environment auto-detection
-    applies; a second call (from this shim or from an external launcher
-    that beat us to it) is a no-op instead of the RuntimeError jax raises
-    on double initialization. Must run before any jax device use.
+    ``None`` arguments leave jax's environment auto-detection to fill them
+    in; a second call (from this shim or from an external launcher that
+    beat us to it) is a no-op instead of the RuntimeError jax raises on
+    double initialization. Must run before any jax device use.
     """
     global _DIST_INITIALIZED
     if _DIST_INITIALIZED:
         return
     import jax
-    kwargs = {"coordinator_address": coordinator_address,
-              "num_processes": num_processes, "process_id": process_id}
-    kwargs = {k: v for k, v in kwargs.items() if v is not None}
     try:
-        jax.distributed.initialize(**kwargs)
+        jax.distributed.initialize(coordinator_address=coordinator_address,
+                                   num_processes=num_processes,
+                                   process_id=process_id)
     except RuntimeError as e:
         # jax: "jax.distributed.initialize should only be called once"
         if "once" not in str(e):

@@ -95,7 +95,8 @@ def _rope_rotate(x, pos, theta: float):
     cannot drift between them."""
     hd = x.shape[-1]
     half = hd // 2
-    j = jax.lax.broadcasted_iota(jnp.float32, (1, half), 1)
+    # Mosaic has no float iota: generate int32 and convert
+    j = jax.lax.broadcasted_iota(jnp.int32, (1, half), 1).astype(jnp.float32)
     ang = pos * jnp.exp(-(j / half) * math.log(theta))    # (rows, half)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     x1, x2 = x[:, :half], x[:, half:]
@@ -135,6 +136,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, *rest, scale: float, causal: bool,
         pq_ref = rest.pop(0)
         pk_ref = rest.pop(0)
     o_ref, lse_ref, acc_ref, m_ref, l_ref = rest
+    b = pl.program_id(0)
     qi = pl.program_id(2)
     ki = pl.program_id(3)
     nk = pl.num_programs(3)
@@ -166,7 +168,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, *rest, scale: float, causal: bool,
         mask = _visibility_mask(
             s.shape, q_start, k_start, causal=causal, window=window,
             seq_k=seq_k,
-            kv_offset=off_ref[0, 0] if has_offsets else None)
+            kv_offset=off_ref[b] if has_offsets else None)
         s = jnp.where(mask, s, NEG_INF)
         m_prev = m_ref[...]                               # (bq, 1)
         m_cur = jnp.max(s, axis=-1, keepdims=True)
@@ -220,9 +222,10 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
     inputs = (q, k, v)
     off_specs = []
     if has_offsets:
-        inputs = inputs + (jnp.asarray(kv_offsets, jnp.int32).reshape(B, 1),)
-        off_specs = [pl.BlockSpec((1, 1), lambda b, h, qi, ki: (b, 0),
-                                  memory_space=pltpu.SMEM)]
+        # the whole (B,) vector sits in SMEM; the kernel reads its row's
+        # scalar (a (1,) block of a 1-D SMEM array is not a legal tile)
+        inputs = inputs + (jnp.asarray(kv_offsets, jnp.int32).reshape(B),)
+        off_specs = [pl.BlockSpec(memory_space=pltpu.SMEM)]
 
     out, lse = pl.pallas_call(
         functools.partial(
@@ -319,9 +322,8 @@ def flash_attention_rope_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
     inputs = (q, k, v)
     extra_specs = []
     if has_offsets:
-        inputs = inputs + (jnp.asarray(kv_offsets, jnp.int32).reshape(B, 1),)
-        extra_specs = [pl.BlockSpec((1, 1), lambda b, h, qi, ki: (b, 0),
-                                    memory_space=pltpu.SMEM)]
+        inputs = inputs + (jnp.asarray(kv_offsets, jnp.int32).reshape(B),)
+        extra_specs = [pl.BlockSpec(memory_space=pltpu.SMEM)]
     inputs = inputs + (posq, posk)
     extra_specs = extra_specs + [
         pl.BlockSpec((1, bq, 1), lambda b, h, qi, ki: (b, qi, 0)),

@@ -97,6 +97,7 @@ def _flash_decode_kernel(pos_ref, off_ref, q_ref, k_ref, v_ref, o_ref,
                          window: Optional[int], ring: bool, seq_k: int,
                          block_k: int, has_offsets: bool,
                          rope_theta: Optional[float] = None):
+    b = pl.program_id(0)
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
 
@@ -106,7 +107,7 @@ def _flash_decode_kernel(pos_ref, off_ref, q_ref, k_ref, v_ref, o_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    pos = pos_ref[0, 0]
+    pos = pos_ref[b]
     k_start = ki * block_k
     # dynamic block skip: a full-layout block is dead if its first slot is
     # beyond pos (causal) or its last slot is older than the window. Ring
@@ -127,7 +128,7 @@ def _flash_decode_kernel(pos_ref, off_ref, q_ref, k_ref, v_ref, o_ref,
             # cached keys are rotated at write time; only the fresh query
             # row still needs its rotation — fused here, by the row's
             # logical position (pos minus any left pad)
-            qpos = pos - (off_ref[0, 0] if has_offsets else 0)
+            qpos = pos - (off_ref[b] if has_offsets else 0)
             q = _rope_rotate(
                 q, jnp.zeros((q.shape[0], 1), jnp.float32) + qpos,
                 rope_theta)
@@ -138,7 +139,7 @@ def _flash_decode_kernel(pos_ref, off_ref, q_ref, k_ref, v_ref, o_ref,
         slot = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         mask = _slot_visibility(
             slot, pos, seq_k=seq_k, window=window, ring=ring,
-            offset=off_ref[0, 0] if has_offsets else None)
+            offset=off_ref[b] if has_offsets else None)
         s = jnp.where(mask, s, NEG_INF)
         m_prev = m_ref[...]                               # (g, 1)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -185,17 +186,13 @@ def flash_decode_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
         k = jnp.pad(k, ((0, 0), (0, 0), (0, Sp - S), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, 0), (0, Sp - S), (0, 0)))
     qg = q.reshape(B, KV, g, hd)
-    # per-row (B, 1) SMEM refs; a scalar pos broadcasts to every row
-    pos_arr = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1),
-                               (B,)).reshape(B, 1)
+    # per-row (B,) vectors held whole in SMEM (the kernel reads row b's
+    # scalar); a scalar pos broadcasts to every row
+    pos_arr = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (B,))
     has_offsets = offsets is not None
-    if has_offsets:
-        off_arr = jnp.asarray(offsets, jnp.int32).reshape(B, 1)
-    else:
-        off_arr = jnp.zeros((1, 1), jnp.int32)
-    off_spec = pl.BlockSpec(
-        (1, 1), (lambda b, h, ki: (b, 0)) if has_offsets
-        else (lambda b, h, ki: (0, 0)), memory_space=pltpu.SMEM)
+    off_arr = (jnp.asarray(offsets, jnp.int32).reshape(B) if has_offsets
+               else jnp.zeros((1,), jnp.int32))
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
 
     out = pl.pallas_call(
         functools.partial(
@@ -204,9 +201,8 @@ def flash_decode_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
             rope_theta=rope_theta),
         grid=(B, KV, Sp // bk),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda b, h, ki: (b, 0),
-                         memory_space=pltpu.SMEM),
-            off_spec,
+            smem,
+            smem,
             pl.BlockSpec((1, 1, g, hd), lambda b, h, ki: (b, h, 0, 0)),
             pl.BlockSpec((1, 1, bk, hd), lambda b, h, ki: (b, h, ki, 0)),
             pl.BlockSpec((1, 1, bk, hd), lambda b, h, ki: (b, h, ki, 0)),

@@ -238,6 +238,12 @@ def gbn_forward(xg: jax.Array, gamma: jax.Array, beta: jax.Array, *,
 # mamba chunk scan
 # ---------------------------------------------------------------------------
 
+class KernelFallbackWarning(UserWarning):
+    """A shape the kernel cannot tile legally: the op degrades to a larger
+    untiled block or to the jnp oracle. A run that must prove the kernels
+    ran (``chip_smoke.py``) turns this category into an error."""
+
+
 # d_inner values we already warned about (one warning per distinct shape,
 # not per trace): sub-lane-aligned fallback tiles and oracle fallbacks
 _TILE_WARNED: Set[Tuple[int, str]] = set()
@@ -246,7 +252,7 @@ _TILE_WARNED: Set[Tuple[int, str]] = set()
 def _warn_once(di: int, kind: str, msg: str) -> None:
     if (di, kind) not in _TILE_WARNED:
         _TILE_WARNED.add((di, kind))
-        warnings.warn(msg, stacklevel=3)
+        warnings.warn(msg, KernelFallbackWarning, stacklevel=3)
 
 
 # largest whole-axis (untiled) d_inner the kernel will take when no

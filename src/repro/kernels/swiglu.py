@@ -37,6 +37,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_ROW_TILE = 128
 
@@ -46,18 +47,37 @@ def _pad_rows(x: jax.Array, mult: int) -> jax.Array:
     return jnp.pad(x, ((0, pad), (0, 0))) if pad else x
 
 
-def _f_tile(F: int) -> int:
-    """Largest standard hidden tile dividing F (F is 128-aligned here)."""
+# the backward holds a (row_tile, d) f32 dx block and four (d, tile)
+# weight buffers at once: at d = 2048 that is past v5e's 16 MiB default
+# scoped VMEM, so it asks for more (of the 128 MiB the core has)
+_BWD_VMEM_LIMIT = 64 * 1024 * 1024
+
+
+def _f_tile(F: int, cap: int = 512) -> int:
+    """Largest standard hidden tile (<= cap) dividing F (F is 128-aligned
+    here)."""
     for t in (512, 384, 256, 128):
-        if F % t == 0:
+        if t <= cap and F % t == 0:
             return t
     raise ValueError(f"hidden dim {F} is not 128-aligned")
 
 
+def _dot(a, b):
+    # operands in their stored dtype (bf16 x bf16 products are exact in
+    # f32), accumulated in f32 — no f32 copy of a weight tile in VMEM
+    return jnp.dot(a, b, preferred_element_type=jnp.float32)
+
+
+def _dot_t(a, b):
+    """a @ b.T without materialising the transpose."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
 def _fwd_kernel(x_ref, wg_ref, wu_ref, h_ref, g_ref):
-    xf = x_ref[...].astype(jnp.float32)
-    g = jnp.dot(xf, wg_ref[...].astype(jnp.float32))
-    u = jnp.dot(xf, wu_ref[...].astype(jnp.float32))
+    x = x_ref[...]
+    g = _dot(x, wg_ref[...])
+    u = _dot(x, wu_ref[...])
     g_ref[...] = g.astype(g_ref.dtype)
     h_ref[...] = (g * jax.nn.sigmoid(g) * u).astype(h_ref.dtype)
 
@@ -70,18 +90,20 @@ def _bwd_kernel(x_ref, wg_ref, wu_ref, g_ref, dh_ref, dg_ref, du_ref,
     def _init():
         dx_ref[...] = jnp.zeros_like(dx_ref)
 
-    xf = x_ref[...].astype(jnp.float32)
-    wg = wg_ref[...].astype(jnp.float32)
-    wu = wu_ref[...].astype(jnp.float32)
+    wg = wg_ref[...]
+    wu = wu_ref[...]
     g = g_ref[...].astype(jnp.float32)
     dh = dh_ref[...].astype(jnp.float32)
-    u = jnp.dot(xf, wu)                         # recompute — u is not saved
+    u = _dot(x_ref[...], wu)                    # recompute — u is not saved
     sig = jax.nn.sigmoid(g)
-    du = dh * g * sig
-    dg = dh * u * sig * (1.0 + g * (1.0 - sig))
-    dg_ref[...] = dg.astype(dg_ref.dtype)
-    du_ref[...] = du.astype(du_ref.dtype)
-    dx_ref[...] += jnp.dot(dg, wg.T) + jnp.dot(du, wu.T)
+    du = (dh * g * sig).astype(du_ref.dtype)
+    dg = (dh * u * sig * (1.0 + g * (1.0 - sig))).astype(dg_ref.dtype)
+    dg_ref[...] = dg
+    du_ref[...] = du
+    # dx from the stored-dtype dg/du — the same values the weight-grad
+    # GEMMs outside the kernel consume
+    dx_ref[...] += (_dot_t(dg.astype(wg.dtype), wg)
+                    + _dot_t(du.astype(wu.dtype), wu))
 
 
 def swiglu_pallas(x: jax.Array, wg: jax.Array, wu: jax.Array, *,
@@ -123,7 +145,7 @@ def swiglu_backward_pallas(x: jax.Array, wg: jax.Array, wu: jax.Array,
     """
     N, d = x.shape
     F = wg.shape[1]
-    bf = _f_tile(F)
+    bf = _f_tile(F, cap=256)
     xp = _pad_rows(x, row_tile)
     gp = _pad_rows(g, row_tile)
     dhp = _pad_rows(dh, row_tile)
@@ -141,6 +163,8 @@ def swiglu_backward_pallas(x: jax.Array, wg: jax.Array, wu: jax.Array,
         out_shape=[jax.ShapeDtypeStruct((xp.shape[0], F), x.dtype),
                    jax.ShapeDtypeStruct((xp.shape[0], F), x.dtype),
                    jax.ShapeDtypeStruct((xp.shape[0], d), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_BWD_VMEM_LIMIT),
         interpret=interpret,
     )(xp, wg, wu, gp, dhp)
     return dx[:N], dg[:N], du[:N]

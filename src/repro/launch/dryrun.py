@@ -57,7 +57,7 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool = False,
     # realistic buffer aliasing: train updates params/opt in place, decode
     # updates the cache in place
     donate = {"train": (0, 1), "decode": (1,), "prefill": ()}[shape.kind]
-    with mesh:
+    with jax.set_mesh(mesh):
         lowered = jax.jit(step_fn, in_shardings=in_shardings,
                           donate_argnums=donate).lower(*args)
         compiled = lowered.compile()

@@ -22,6 +22,7 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
+from jax.sharding import AxisType
 
 # The canonical mesh axis names. Every psum/pmean/all_gather axis argument
 # in src/ traces back to these (lint rule axis-name-literal).
@@ -48,17 +49,26 @@ def init_distributed(coordinator_address: Optional[str] = None,
                            process_id=process_id)
 
 
+def _make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``: the pjit rules
+    (``sharding/rules.py`` + ``with_sharding_constraint`` hints) leave the
+    partitioning of intermediates to GSPMD, and ``shard_map`` regions name
+    their axes explicitly, so neither path wants the sharding-in-types
+    ``Explicit`` axes that jax >= 0.7 picks when ``axis_types`` is omitted."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single-pod (256 chips) or 2x16x16 two-pod (512 chips) mesh."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ((POD_AXIS, DATA_AXIS, MODEL_AXIS) if multi_pod
             else (DATA_AXIS, MODEL_AXIS))
-    return jax.make_mesh(shape, axes)
+    return _make_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Degenerate 1x1 mesh on the real local device (CPU smoke tests)."""
-    return jax.make_mesh((1, 1), (DATA_AXIS, MODEL_AXIS))
+    return _make_mesh((1, 1), (DATA_AXIS, MODEL_AXIS))
 
 
 def make_data_mesh(n_devices: int = 0):
@@ -66,7 +76,7 @@ def make_data_mesh(n_devices: int = 0):
     devices — one mesh slot per GBN device shard; used by the shard_map
     data-parallel trainer (:mod:`repro.train.data_parallel`)."""
     n = n_devices or len(jax.devices())
-    return jax.make_mesh((n,), (DATA_AXIS,))
+    return _make_mesh((n,), (DATA_AXIS,))
 
 
 def make_2d_mesh(n_devices: int = 0, model: int = 0):
@@ -82,7 +92,7 @@ def make_2d_mesh(n_devices: int = 0, model: int = 0):
     m = model or (2 if n > 1 and n % 2 == 0 else 1)
     if n % m:
         raise ValueError(f"{n} devices do not factor into model={m}")
-    return jax.make_mesh((n // m, m), (DATA_AXIS, MODEL_AXIS))
+    return _make_mesh((n // m, m), (DATA_AXIS, MODEL_AXIS))
 
 
 def make_pod_mesh(model: int = 1):
@@ -104,7 +114,7 @@ def make_pod_mesh(model: int = 1):
     if model <= 0 or local % model:
         raise ValueError(
             f"{local} per-process devices do not factor into model={model}")
-    return jax.make_mesh((nproc, local // model, model),
+    return _make_mesh((nproc, local // model, model),
                          (POD_AXIS, DATA_AXIS, MODEL_AXIS))
 
 
@@ -124,7 +134,8 @@ def make_local_mesh(model: int = 1):
         raise ValueError(
             f"{n} local devices do not factor into model={model}")
     return jax.sharding.Mesh(devs.reshape(n // model, model),
-                             (DATA_AXIS, MODEL_AXIS))
+                             (DATA_AXIS, MODEL_AXIS),
+                             axis_types=(AxisType.Auto,) * 2)
 
 
 def global_array(mesh, arr, spec):

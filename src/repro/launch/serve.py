@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.registry import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as T
 from repro.obs import NULL_TRACER, Observability
 from repro.obs.trace import device_trace
@@ -136,6 +137,7 @@ def main() -> None:
                          "aligned under the host spans)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     obs = None
     if args.trace or args.metrics_out or args.device_trace:

@@ -29,6 +29,7 @@ from repro.checkpoint import save as ckpt_save
 from repro.configs.registry import get_config
 from repro.core import DiffusionTracker, LargeBatchConfig, Regime
 from repro.data.synthetic import lm_sequences, token_lm
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models import transformer as T
 from repro.obs import Observability
@@ -92,6 +93,7 @@ def main() -> None:
     ap.add_argument("--metrics-out", default="",
                     help="append the metrics registry as JSONL here")
     args = ap.parse_args()
+    enable_compile_cache()
 
     obs = (Observability() if (args.trace or args.metrics_out) else None)
     tracer = obs.tracer if obs is not None else NULL_TRACER
@@ -124,7 +126,7 @@ def main() -> None:
         pshard = rules.param_shardings(params, mesh, cfg)
         params = jax.device_put(params, pshard)
         step_fn = make_lm_train_step(cfg, lb, regime)
-        mesh_ctx = mesh
+        mesh_ctx = jax.set_mesh(mesh)
     with mesh_ctx:
         step_jit = jax.jit(step_fn, donate_argnums=(0, 1))
 

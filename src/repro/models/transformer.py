@@ -284,7 +284,8 @@ def _chunked_ce(cfg: ModelConfig, x: jax.Array, head: jax.Array,
     """Vocab-chunked streaming softmax CE (beyond-paper memory optimization,
     EXPERIMENTS.md #Perf): the (B, S, V) f32 logits tensor is never
     materialised — logits are computed one V-chunk at a time inside a scan
-    (XLA rematerialises chunks in the backward pass)."""
+    whose body is checkpointed, so the backward pass recomputes each chunk
+    instead of saving all of them."""
     Vp = cfg.padded_vocab
     assert Vp % chunk == 0, (Vp, chunk)
     n = Vp // chunk
@@ -313,7 +314,7 @@ def _chunked_ce(cfg: ModelConfig, x: jax.Array, head: jax.Array,
             jnp.zeros((B_, S_), jnp.float32),
             jnp.zeros((B_, S_), jnp.float32))
     (m_run, s_run, gold), _ = jax.lax.scan(
-        body, init, (head_c, jnp.arange(n)))
+        jax.checkpoint(body), init, (head_c, jnp.arange(n)))
     logz = m_run + jnp.log(jnp.maximum(s_run, 1e-30))
     return (logz - gold).mean()
 

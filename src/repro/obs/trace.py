@@ -139,8 +139,10 @@ NULL_TRACER = Tracer(enabled=False)
 class device_trace:
     """Context manager around ``jax.profiler.start_trace/stop_trace``:
     captures an XLA device trace under ``logdir`` alongside the host spans.
-    Fail-soft: a profiler that cannot start (already active, unsupported
-    backend) degrades to a no-op with a warning instead of killing the run.
+    Off the TPU a profiler that cannot start (already active, unsupported
+    backend) degrades to a no-op with a warning; on the TPU the failure is
+    raised, since a chip run whose trace was asked for and silently not
+    taken would report what it never measured.
     """
 
     def __init__(self, logdir: str):
@@ -152,7 +154,9 @@ class device_trace:
         try:
             jax.profiler.start_trace(self.logdir)
             self._active = True
-        except Exception as e:              # pragma: no cover - env specific
+        except Exception as e:
+            if jax.default_backend() == "tpu":
+                raise
             import warnings
             warnings.warn(f"device trace unavailable: {e}")
         return self

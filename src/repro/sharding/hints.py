@@ -18,13 +18,17 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
-from jax._src import mesh as _mesh_lib
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
 
 def current_mesh():
-    m = _mesh_lib.thread_resources.env.physical_mesh
-    if m is None or m.empty:
+    """The ambient mesh set by ``jax.set_mesh(mesh)`` (as an abstract mesh:
+    axis names and sizes, usable inside ``jit``), or None outside one and
+    inside a ``shard_map`` region, whose axes are all ``Manual`` — there the
+    region's own specs place every array and a constraint has no axis to
+    name."""
+    m = jax.sharding.get_abstract_mesh()
+    if m.empty or AxisType.Auto not in m.axis_types:
         return None
     return m
 

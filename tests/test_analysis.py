@@ -147,7 +147,7 @@ def test_audit_jaxpr_flags_f64():
     def widen(x):
         return x.astype(jnp.float64) * 2.0
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         fs = audit_jaxpr(widen, (jnp.ones((2,), jnp.float32),),
                          name="widen", path="t.py")
     assert any(f.rule == "trace-f64" for f in fs), render(fs)

@@ -157,7 +157,7 @@ def _run_multidev(script: str) -> subprocess.CompletedProcess:
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     env["PYTHONPATH"] = str(REPO / "src") + (
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"   # the child must never reach for a chip
     return subprocess.run([sys.executable, "-c", script],
                           capture_output=True, text=True, env=env,
                           cwd=str(REPO), timeout=600)

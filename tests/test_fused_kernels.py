@@ -410,6 +410,19 @@ def test_swiglu_unaligned_fallback_warns():
                for w in rec)
 
 
+def test_fallback_warning_is_an_error_when_promoted():
+    """The fallback warnings carry their own category, so a run that must
+    prove the kernels ran (chip_smoke.py) can promote exactly them to
+    errors and leave every other warning alone."""
+    x = jnp.ones((8, 128))
+    wg = wu = jnp.ones((128, 100))
+    ops._TILE_WARNED.clear()
+    with warnings_mod.catch_warnings():
+        warnings_mod.simplefilter("error", ops.KernelFallbackWarning)
+        with pytest.raises(ops.KernelFallbackWarning, match="swiglu"):
+            ops.swiglu(x, wg, wu)
+
+
 # ---------------------------------------------------------------------------
 # int8 paged KV cache
 # ---------------------------------------------------------------------------

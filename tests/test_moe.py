@@ -105,7 +105,7 @@ def test_ep_shard_map_equals_fallback():
     toks = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0,
                               cfg.vocab_size)
     l0, _ = T.forward(params, cfg, toks)
-    with make_host_mesh():
+    with jax.set_mesh(make_host_mesh()):
         l1, _ = jax.jit(lambda p, t: T.forward(p, cfg, t))(params, toks)
     np.testing.assert_allclose(l0, l1, rtol=2e-4, atol=2e-4)
 

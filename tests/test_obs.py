@@ -162,6 +162,30 @@ def test_observability_bundle(tmp_path):
     assert obs.tracer.events == [] and obs.registry.names() == []
 
 
+@pytest.mark.tier0
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+def test_device_trace_failure_raises_only_on_tpu(monkeypatch, tmp_path,
+                                                 backend):
+    """A profiler that cannot start degrades to a warning off the TPU; on
+    the TPU the run stops rather than report an untraced window."""
+    import jax
+    from repro.obs.trace import device_trace
+
+    def refuse(logdir):
+        raise RuntimeError("profiler already active")
+
+    monkeypatch.setattr(jax.profiler, "start_trace", refuse)
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if backend == "tpu":
+        with pytest.raises(RuntimeError, match="already active"):
+            with device_trace(str(tmp_path)):
+                pass
+    else:
+        with pytest.warns(UserWarning, match="device trace unavailable"):
+            with device_trace(str(tmp_path)):
+                pass
+
+
 # ---------------------------------------------------------------------------
 # MetricsLogger dedup: one implementation, both legacy import paths
 # ---------------------------------------------------------------------------

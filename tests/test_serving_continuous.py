@@ -324,7 +324,7 @@ def test_sharded_engine_matches_unsharded_subprocess():
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     env["PYTHONPATH"] = str(repo / "src") + (
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"   # the child must never reach for a chip
     proc = subprocess.run([sys.executable, "-c", SHARDED_ENGINE_SCRIPT],
                           capture_output=True, text=True, env=env,
                           cwd=str(repo), timeout=900)

@@ -58,7 +58,7 @@ def test_hint_noop_without_mesh():
 
 
 def test_hint_rank_mismatch_raises():
-    with make_host_mesh():
+    with jax.set_mesh(make_host_mesh()):
         with pytest.raises(ValueError):
             hint(jnp.ones((2, 2)), "dp")
 
@@ -79,7 +79,7 @@ def test_train_step_under_host_mesh():
     step = make_lm_train_step(cfg, lb, regime, remat=True, seq_parallel=True)
     batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (2, 32), 0,
                                           cfg.vocab_size)}
-    with mesh:
+    with jax.set_mesh(mesh):
         p2, o2, m = jax.jit(step)(params, opt, batch, jnp.int32(0),
                                   jax.random.PRNGKey(2))
     assert not jnp.isnan(m["loss"])
