@@ -14,7 +14,11 @@ syntax, and how to add a rule):
 - ``obs-contract`` — any function taking ``obs=`` defaults it to ``None``
   (the zero-cost-when-absent contract), span names are
   ``<subsystem>.<signal>`` and metric names ``<subsystem>/<signal>``
-  (docs/observability.md grammar).
+  (docs/observability.md grammar); every ``jax.named_scope`` takes a
+  literal ``[a-z][a-z0-9_]*`` name (an f-string such as ``f"stage{i}"``
+  counts when its literal parts keep to it) and every ``pallas_call``
+  passes a literal ``name="<kernel>_<fwd|bwd>[_<part>]"``, the names a
+  device trace is read by.
 - ``prng-reuse`` — a PRNG key fed to two ``jax.random.*`` consumers
   without an intervening ``split``/``fold_in`` silently correlates the
   two draws.
@@ -179,7 +183,47 @@ def rule_host_sync(tree: ast.AST, relpath: str,
 
 SPAN_NAME_RE = re.compile(r"^[a-z0-9_]+(\.[a-z0-9_]+)+$")
 METRIC_NAME_RE = re.compile(r"^[a-z0-9_]+(/[a-z0-9_]+)+$")
+SCOPE_NAME_RE = re.compile(r"^[a-z][a-z0-9_]*$")
+KERNEL_NAME_RE = re.compile(r"^[a-z][a-z0-9_]*_(fwd|bwd)(_[a-z0-9]+)*$")
 _METRIC_METHODS = {"observe", "set", "inc"}
+
+
+def _callee(node: ast.Call) -> Optional[str]:
+    f = node.func
+    return f.attr if isinstance(f, ast.Attribute) else \
+        f.id if isinstance(f, ast.Name) else None
+
+
+def _literal_scope(arg: ast.AST) -> bool:
+    """A string literal of the scope grammar, or an f-string whose literal
+    parts keep to it and start it (``f"block{i}"``)."""
+    if isinstance(arg, ast.Constant) and isinstance(arg.value, str):
+        return bool(SCOPE_NAME_RE.match(arg.value))
+    if not isinstance(arg, ast.JoinedStr) or not arg.values:
+        return False
+    parts = [v.value if isinstance(v, ast.Constant) else "0"
+             for v in arg.values]
+    return isinstance(arg.values[0], ast.Constant) and bool(
+        SCOPE_NAME_RE.match("".join(parts)))
+
+
+def _name_findings(node: ast.Call, relpath: str) -> List[Finding]:
+    callee = _callee(node)
+    if callee == "named_scope" and not (
+            node.args and _literal_scope(node.args[0])):
+        return [Finding(relpath, node.lineno, "obs-contract",
+                        "named_scope takes a literal [a-z][a-z0-9_]* name "
+                        "(docs/observability.md: scopes and kernel names)")]
+    if callee == "pallas_call":
+        name = next((kw.value for kw in node.keywords if kw.arg == "name"),
+                    None)
+        if not (isinstance(name, ast.Constant)
+                and isinstance(name.value, str)
+                and KERNEL_NAME_RE.match(name.value)):
+            return [Finding(relpath, node.lineno, "obs-contract",
+                            "pallas_call without a literal "
+                            "name=\"<kernel>_<fwd|bwd>[_<part>]\"")]
+    return []
 
 
 def _arg_default(fn: ast.AST, name: str):
@@ -210,6 +254,8 @@ def rule_obs_contract(tree: ast.AST, relpath: str,
                     f"'{node.name}' takes obs= but does not default it to "
                     f"None — call sites must stay zero-cost un-observed"))
             continue
+        if isinstance(node, ast.Call):
+            out.extend(_name_findings(node, relpath))
         if not (isinstance(node, ast.Call)
                 and isinstance(node.func, ast.Attribute) and node.args
                 and isinstance(node.args[0], ast.Constant)
@@ -397,8 +443,8 @@ RULES: Dict[str, RuleFn] = {
 CATALOG: Dict[str, str] = {
     "shard-map-import": "shard_map imported outside core/compat.py",
     "host-sync": "per-metric device syncs / .item() on a hot path",
-    "obs-contract": "obs= without None default, or span/metric name "
-                    "off the naming grammar",
+    "obs-contract": "obs= without None default, or span/metric/scope/"
+                    "kernel name off the naming grammar",
     "prng-reuse": "PRNG key consumed twice without split/fold_in",
     "axis-name-literal": "collective axis name spelled as a string literal "
                          "instead of a launch.mesh constant",

@@ -67,6 +67,7 @@ def _cascaded_ema(run: jax.Array, per_ghost: jax.Array, eta: float) -> jax.Array
         "g,gc->c", decay, per_ghost)
 
 
+@jax.named_scope("gbn")
 def gbn_apply(params: Params, state: Params, x: jax.Array, *,
               ghost_batch_size: int, eps: float = 1e-5,
               momentum: float = 0.1, training: bool = True,
@@ -121,6 +122,7 @@ def gbn_apply(params: Params, state: Params, x: jax.Array, *,
     return y.astype(dt), new_state
 
 
+@jax.named_scope("gbn")
 def equal_weight_bn_apply(params: Params, state: Params, x: jax.Array, *,
                           eps: float = 1e-5, momentum: float = 0.1,
                           training: bool = True) -> Tuple[jax.Array, Params]:

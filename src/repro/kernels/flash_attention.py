@@ -232,6 +232,7 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
             _flash_kernel, scale=1.0 / math.sqrt(hd), causal=causal,
             window=window, block_q=bq, block_k=bk, seq_k=S,
             has_offsets=has_offsets),
+        name="flash_attention_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, bq, hd), lambda b, h, qi, ki: (b, h, qi, 0)),
@@ -335,6 +336,7 @@ def flash_attention_rope_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
             _flash_kernel, scale=1.0 / math.sqrt(hd), causal=causal,
             window=window, block_q=bq, block_k=bk, seq_k=S,
             has_offsets=has_offsets, rope_theta=theta),
+        name="flash_attention_rope_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, bq, hd), lambda b, h, qi, ki: (b, h, qi, 0)),
@@ -575,6 +577,7 @@ def flash_attention_backward_pallas(
             functools.partial(
                 _flash_bwd_fused_kernel, scale=scale, causal=causal,
                 window=window, block_q=bq, block_k=bk, seq_k=S),
+            name="flash_attention_bwd_fused",
             grid=(B, H, Sp // bk, Tp // bq),
             in_specs=[qT_spec, kvT_spec, kvT_spec, qT_spec, rowT_spec,
                       rowT_spec],
@@ -599,6 +602,7 @@ def flash_attention_backward_pallas(
             functools.partial(
                 _flash_bwd_dq_kernel, scale=scale, causal=causal,
                 window=window, block_q=bq, block_k=bk, seq_k=S),
+            name="flash_attention_bwd_dq",
             grid=(B, H, Tp // bq, Sp // bk),
             in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
             out_specs=q_spec,
@@ -611,6 +615,7 @@ def flash_attention_backward_pallas(
             functools.partial(
                 _flash_bwd_dkv_kernel, scale=scale, causal=causal,
                 window=window, block_q=bq, block_k=bk, seq_k=S),
+            name="flash_attention_bwd_dkv",
             grid=(B, H, Sp // bk, Tp // bq),
             in_specs=[qT_spec, kvT_spec, kvT_spec, qT_spec, rowT_spec,
                       rowT_spec],

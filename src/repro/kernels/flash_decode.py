@@ -199,6 +199,7 @@ def flash_decode_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
             _flash_decode_kernel, scale=1.0 / math.sqrt(hd), window=window,
             ring=ring, seq_k=S, block_k=bk, has_offsets=has_offsets,
             rope_theta=rope_theta),
+        name="flash_decode_fwd",
         grid=(B, KV, Sp // bk),
         in_specs=[
             smem,
@@ -428,6 +429,7 @@ def flash_decode_paged_pallas(q: jax.Array, kp: jax.Array, vp: jax.Array,
             window=window, page_size=ps, n_blocks=NB,
             has_offsets=has_offsets, quantized=quantized,
             rope_theta=rope_theta),
+        name="flash_decode_paged_fwd",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, g, hd), q.dtype),
         interpret=interpret,

@@ -91,6 +91,7 @@ def rmsnorm_residual_pallas(x: jax.Array, r: jax.Array, scale: jax.Array, *,
     row_spec = pl.BlockSpec((row_tile, d), lambda i: (i, 0))
     y, s = pl.pallas_call(
         functools.partial(_fwd_kernel, eps=eps),
+        name="rmsnorm_residual_fwd",
         grid=(nr,),
         in_specs=[row_spec, row_spec,
                   pl.BlockSpec((1, d), lambda i: (0, 0))],
@@ -123,6 +124,7 @@ def rmsnorm_residual_backward_pallas(s: jax.Array, scale: jax.Array,
     vec_spec = pl.BlockSpec((1, d), lambda i: (0, 0))
     dx, dscale = pl.pallas_call(
         functools.partial(_bwd_kernel, eps=eps),
+        name="rmsnorm_residual_bwd",
         grid=(nr,),
         in_specs=[row_spec, vec_spec, row_spec, row_spec],
         out_specs=[row_spec, vec_spec],

@@ -186,6 +186,7 @@ def gbn_forward_pallas(xg: jax.Array, gamma: jax.Array, beta: jax.Array, *,
 
     sums, sqs = pl.pallas_call(
         functools.partial(_stats_kernel, n_rows=t.rows),
+        name="gbn_fwd_stats",
         grid=(G, nc, nr),
         in_specs=[x_spec],
         out_specs=[stat_spec, stat_spec],
@@ -197,6 +198,7 @@ def gbn_forward_pallas(xg: jax.Array, gamma: jax.Array, beta: jax.Array, *,
 
     y = pl.pallas_call(
         functools.partial(_normalize_kernel, eps=eps),
+        name="gbn_fwd_normalize",
         grid=(G, nc, nr),
         in_specs=[x_spec, stat_spec, stat_spec, param_spec, param_spec],
         out_specs=x_spec,
@@ -235,6 +237,7 @@ def gbn_backward_pallas(xg: jax.Array, gamma: jax.Array, mu: jax.Array,
     mul = _to_lanes(t, mu)
     sdy, sdyxh = pl.pallas_call(
         _bwd_stats_kernel,
+        name="gbn_bwd_stats",
         grid=(G, nc, nr),
         in_specs=[x_spec, x_spec, stat_spec, stat_spec],
         out_specs=[stat_spec, stat_spec],
@@ -255,6 +258,7 @@ def gbn_backward_pallas(xg: jax.Array, gamma: jax.Array, mu: jax.Array,
 
     dx = pl.pallas_call(
         _bwd_dx_kernel,
+        name="gbn_bwd_dx",
         grid=(G, nc, nr),
         in_specs=[x_spec, x_spec] + [stat_spec] * 4,
         out_specs=x_spec,

@@ -74,6 +74,7 @@ def mamba_chunk_pallas(xc: jax.Array, dt: jax.Array, Bm: jax.Array,
 
     y, hout = pl.pallas_call(
         functools.partial(_mamba_kernel, chunk=c),
+        name="mamba_scan_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, c, dit), lambda b, d: (b, 0, d)),
@@ -183,6 +184,7 @@ def mamba_chunk_backward_pallas(xc: jax.Array, dt: jax.Array, Bm: jax.Array,
 
     dxc, ddt, dB, dC, dA_b, dh0 = pl.pallas_call(
         functools.partial(_mamba_bwd_kernel, chunk=c),
+        name="mamba_scan_bwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, c, dit), lambda b, d: (b, 0, d)),

@@ -10,6 +10,11 @@ wired up with ``jax.custom_vjp`` (see docs/kernels.md for each op's
 forward/backward contract and residual layout) — ``jax.grad`` through the
 ``use_kernels=True`` training paths never falls back to
 autodiff-through-interpret or to an oracle forward replay.
+
+Each public op runs under a ``jax.named_scope`` of its kernel's name, and
+every ``pallas_call`` under ``kernels/`` passes ``name=<kernel>_<fwd|bwd>
+[_<part>]``, so a compiled instruction's ``op_name`` metadata says which
+kernel it belongs to, forward or backward (docs/observability.md).
 """
 from __future__ import annotations
 
@@ -76,6 +81,7 @@ def _flash_bwd(causal, window, block_q, block_k, res, do):
 _flash_attention.defvjp(_flash_fwd, _flash_bwd)
 
 
+@jax.named_scope("flash_attention")
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True,
                     window: Optional[int] = None,
@@ -110,6 +116,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("flash_decode")
 def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array, pos: jax.Array, *,
                  window: Optional[int] = None, ring: bool = False,
                  offsets: Optional[jax.Array] = None,
@@ -147,6 +154,7 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array, pos: jax.Array, *,
     return out.reshape(B, 1, H, hd)
 
 
+@jax.named_scope("flash_decode_paged")
 def flash_decode_paged(q: jax.Array, kp: jax.Array, vp: jax.Array,
                        pt: jax.Array, pos: jax.Array, *,
                        window: Optional[int] = None,
@@ -185,6 +193,7 @@ def flash_decode_paged(q: jax.Array, kp: jax.Array, vp: jax.Array,
     return out.reshape(B, 1, H, hd)
 
 
+@jax.named_scope("flash_attention")
 def flash_attention_hm(q: jax.Array, k: jax.Array, v: jax.Array, *,
                        causal: bool = True, window: Optional[int] = None,
                        block_q: int = DEFAULT_BLOCK_Q,
@@ -223,6 +232,7 @@ def _gbn_bwd(eps, res, cts):
 _gbn_forward.defvjp(_gbn_fwd, _gbn_bwd)
 
 
+@jax.named_scope("gbn")
 def gbn_forward(xg: jax.Array, gamma: jax.Array, beta: jax.Array, *,
                 eps: float = 1e-5) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """xg: (G, R, C) -> (y, mu (G,C), var (G,C)).
@@ -290,7 +300,7 @@ def _mamba_tile(di: int) -> Optional[int]:
     return None
 
 
-@jax.custom_vjp
+@jax.named_scope("mamba_scan")
 def mamba_chunk(xc: jax.Array, dt: jax.Array, Bm: jax.Array, Cm: jax.Array,
                 A: jax.Array, h0: jax.Array
                 ) -> Tuple[jax.Array, jax.Array]:
@@ -301,6 +311,11 @@ def mamba_chunk(xc: jax.Array, dt: jax.Array, Bm: jax.Array, Cm: jax.Array,
     scratch inside the backward kernel. Validated against
     :func:`repro.kernels.ref.mamba_chunk_vjp_ref`.
     """
+    return _mamba_chunk(xc, dt, Bm, Cm, A, h0)
+
+
+@jax.custom_vjp
+def _mamba_chunk(xc, dt, Bm, Cm, A, h0):
     dit = _mamba_tile(xc.shape[-1])
     if dit is None:
         return ref.mamba_chunk_ref(xc, dt, Bm, Cm, A, h0)
@@ -309,7 +324,7 @@ def mamba_chunk(xc: jax.Array, dt: jax.Array, Bm: jax.Array, Cm: jax.Array,
 
 
 def _mamba_chunk_fwd(xc, dt, Bm, Cm, A, h0):
-    out = mamba_chunk(xc, dt, Bm, Cm, A, h0)
+    out = _mamba_chunk(xc, dt, Bm, Cm, A, h0)
     # residuals: the inputs only — the backward kernel recomputes the state
     # trajectory per chunk in VMEM, so nothing (B, c, di, ds)-sized is saved
     return out, (xc, dt, Bm, Cm, A, h0)
@@ -326,7 +341,7 @@ def _mamba_chunk_bwd(res, cts):
                                        di_tile=dit, interpret=_interpret())
 
 
-mamba_chunk.defvjp(_mamba_chunk_fwd, _mamba_chunk_bwd)
+_mamba_chunk.defvjp(_mamba_chunk_fwd, _mamba_chunk_bwd)
 
 
 # ---------------------------------------------------------------------------
@@ -367,6 +382,7 @@ def _flash_rope_bwd(theta, causal, window, block_q, block_k, res, do):
 _flash_attention_rope.defvjp(_flash_rope_fwd, _flash_rope_bwd)
 
 
+@jax.named_scope("flash_attention_rope")
 def flash_attention_rope(q: jax.Array, k: jax.Array, v: jax.Array,
                          positions: jax.Array, *, theta: float,
                          causal: bool = True,
@@ -459,6 +475,7 @@ def _rmsnorm_residual_bwd(eps, res, cts):
 _rmsnorm_residual.defvjp(_rmsnorm_residual_fwd, _rmsnorm_residual_bwd)
 
 
+@jax.named_scope("rmsnorm_residual")
 def rmsnorm_residual(x: jax.Array, r: jax.Array, scale: jax.Array, *,
                      eps: float = 1e-6) -> Tuple[jax.Array, jax.Array]:
     """Fused residual-add + RMSNorm: returns ``(rmsnorm(x + r) * scale,
@@ -471,7 +488,7 @@ def rmsnorm_residual(x: jax.Array, r: jax.Array, scale: jax.Array, *,
     return _rmsnorm_residual(x, r, scale, eps)
 
 
-@jax.custom_vjp
+@jax.named_scope("swiglu")
 def swiglu(x: jax.Array, wg: jax.Array, wu: jax.Array) -> jax.Array:
     """Fused SwiGLU front half: ``silu(x @ wg) * (x @ wu)`` over (..., d)
     with one pass over x and a single saved hidden activation (the gate
@@ -481,6 +498,11 @@ def swiglu(x: jax.Array, wg: jax.Array, wu: jax.Array) -> jax.Array:
     against :func:`repro.kernels.ref.swiglu_vjp_ref`. Non-128-multiple
     ``d``/hidden dims fall back to the oracle (one-time warning).
     """
+    return _swiglu(x, wg, wu)
+
+
+@jax.custom_vjp
+def _swiglu(x, wg, wu):
     h, _ = _swiglu_impl(x, wg, wu)
     return h
 
@@ -526,4 +548,4 @@ def _swiglu_bwd(res, dh):
     return dx.astype(x.dtype).reshape(x.shape), dwg, dwu
 
 
-swiglu.defvjp(_swiglu_fwd, _swiglu_bwd)
+_swiglu.defvjp(_swiglu_fwd, _swiglu_bwd)

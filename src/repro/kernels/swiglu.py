@@ -122,6 +122,7 @@ def swiglu_pallas(x: jax.Array, wg: jax.Array, wu: jax.Array, *,
     out_spec = pl.BlockSpec((row_tile, bf), lambda i, j: (i, j))
     h, g = pl.pallas_call(
         _fwd_kernel,
+        name="swiglu_fwd",
         grid=(nr, nf),
         in_specs=[pl.BlockSpec((row_tile, d), lambda i, j: (i, 0)),
                   pl.BlockSpec((d, bf), lambda i, j: (0, j)),
@@ -153,6 +154,7 @@ def swiglu_backward_pallas(x: jax.Array, wg: jax.Array, wu: jax.Array,
     hid_spec = pl.BlockSpec((row_tile, bf), lambda i, j: (i, j))
     dg, du, dx = pl.pallas_call(
         _bwd_kernel,
+        name="swiglu_bwd",
         grid=(nr, nf),
         in_specs=[pl.BlockSpec((row_tile, d), lambda i, j: (i, 0)),
                   pl.BlockSpec((d, bf), lambda i, j: (0, j)),

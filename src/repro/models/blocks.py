@@ -7,6 +7,13 @@ mixer (attention / sliding-window attention / mamba), and a feed-forward
 The repeating ``body_pattern`` is executed as a ``lax.scan`` over stacked
 parameters (one stack of ``body_repeats`` per pattern slot) so that HLO size
 and compile time stay flat in network depth.
+
+Each sublayer runs under a ``jax.named_scope`` (the HLO ``op_name``
+metadata a device trace is read by, docs/observability.md): ``norm`` (the
+pre-norms and the fused residual-add + norm, set in :mod:`.layers`),
+``attention`` (projections, qk-norm, RoPE and the attention kernel or its
+jnp twin, with the residual add where it is not fused into the next norm),
+``ssm``, and ``mlp`` or ``moe`` (with their residual add).
 """
 from __future__ import annotations
 
@@ -110,6 +117,40 @@ def block_cache(cfg: ModelConfig, spec: LayerSpec, batch: int, max_len: int,
     return c
 
 
+@jax.named_scope("attention")
+def _attention(p: Params, cfg: ModelConfig, h: jax.Array,
+               cache: Optional[Params], pos, positions, *,
+               window: Optional[int], decode: bool, prefill: bool,
+               causal: bool, offsets, use_kernels: bool):
+    """The attention mixer in its three cache modes: (output, the layer's
+    new KV cache or None)."""
+    if decode:
+        return L.attention_decode(p, cfg, h, cache["attn"], pos,
+                                  window=window, offsets=offsets,
+                                  use_kernels=use_kernels)
+    if prefill:
+        return L.attention_prefill(p, cfg, h, positions, cache["attn"],
+                                   window=window, offsets=offsets,
+                                   use_kernels=use_kernels)
+    ax = _tp_axis(p["wq"].shape[-1], cfg.n_heads * cfg.head_dim)
+    if ax is None:
+        return L.attention_full(p, cfg, h, positions, window=window,
+                                causal=causal, use_kernels=use_kernels), None
+    # Megatron attention: head-split qkv (column-parallel) + head-split wo
+    # (row-parallel). The whole sublayer is one partial-sum region:
+    # identity-fwd/psum-bwd on everything replicated entering it (the
+    # stream AND the per-head-dim qk_norm scales), psum-fwd/identity-bwd on
+    # the way out.
+    mp = dict(p)
+    for nk in ("q_norm", "k_norm"):
+        if nk in mp:
+            mp[nk] = {"scale": EP.region_in(mp[nk]["scale"], ax)}
+    return EP.region_out(
+        L.attention_full(mp, cfg, EP.region_in(h, ax), positions,
+                         window=window, causal=causal,
+                         use_kernels=use_kernels), ax), None
+
+
 def block_apply(params: Params, cfg: ModelConfig, spec: LayerSpec,
                 x: jax.Array, *,
                 positions: Optional[jax.Array] = None,
@@ -136,70 +177,49 @@ def block_apply(params: Params, cfg: ModelConfig, spec: LayerSpec,
 
     if spec.cross_attn:
         h = L.norm_apply(cfg, params["norm_x"], x)
-        if decode or prefill:
-            y = L.cross_attention_apply(
-                params["cross"], cfg, h, cache["cross_k"], cache["cross_v"])
-            new_cache["cross_k"] = cache["cross_k"]
-            new_cache["cross_v"] = cache["cross_v"]
-        else:
-            k, v = L.cross_kv(params["cross"], cfg, memory)
-            y = L.cross_attention_apply(params["cross"], cfg, h, k, v)
-        x = x + y
+        with jax.named_scope("attention"):
+            if decode or prefill:
+                y = L.cross_attention_apply(
+                    params["cross"], cfg, h, cache["cross_k"],
+                    cache["cross_v"])
+                new_cache["cross_k"] = cache["cross_k"]
+                new_cache["cross_v"] = cache["cross_v"]
+            else:
+                k, v = L.cross_kv(params["cross"], cfg, memory)
+                y = L.cross_attention_apply(params["cross"], cfg, h, k, v)
+            x = x + y
 
     y_mix = None                 # mixer output, residual-add deferred to ff
     if spec.mixer in ("attn", "swa"):
         window = cfg.sliding_window if spec.mixer == "swa" else None
         h = L.norm_apply(cfg, params["norm1"], x)
-        if decode:
-            y_mix, kvc = L.attention_decode(params["mixer"], cfg, h,
-                                            cache["attn"], pos, window=window,
-                                            offsets=offsets,
-                                            use_kernels=use_kernels)
+        y_mix, kvc = _attention(params["mixer"], cfg, h, cache, pos,
+                                positions, window=window, decode=decode,
+                                prefill=prefill, causal=causal,
+                                offsets=offsets, use_kernels=use_kernels)
+        if kvc is not None:
             new_cache["attn"] = kvc
-        elif prefill:
-            y_mix, kvc = L.attention_prefill(params["mixer"], cfg, h,
-                                             positions, cache["attn"],
-                                             window=window, offsets=offsets,
-                                             use_kernels=use_kernels)
-            new_cache["attn"] = kvc
-        else:
-            ax = _tp_axis(params["mixer"]["wq"].shape[-1],
-                          cfg.n_heads * cfg.head_dim)
-            if ax is not None:
-                # Megatron attention: head-split qkv (column-parallel) +
-                # head-split wo (row-parallel). The whole sublayer is one
-                # partial-sum region: identity-fwd/psum-bwd on everything
-                # replicated entering it (the stream AND the per-head-dim
-                # qk_norm scales), psum-fwd/identity-bwd on the way out.
-                mp = dict(params["mixer"])
-                for nk in ("q_norm", "k_norm"):
-                    if nk in mp:
-                        mp[nk] = {"scale": EP.region_in(mp[nk]["scale"], ax)}
-                y_mix = EP.region_out(
-                    L.attention_full(mp, cfg, EP.region_in(h, ax), positions,
-                                     window=window, causal=causal,
-                                     use_kernels=use_kernels), ax)
-            else:
-                y_mix = L.attention_full(params["mixer"], cfg, h, positions,
-                                         window=window, causal=causal,
-                                         use_kernels=use_kernels)
     elif spec.mixer == "ssm":
         h = L.norm_apply(cfg, params["norm1"], x)
-        if decode:
-            y_mix, sc = SSM.ssm_decode(params["mixer"], cfg, h, cache["ssm"])
-            new_cache["ssm"] = sc
-        elif prefill:
-            valid = None
-            if offsets is not None:
-                valid = jnp.arange(x.shape[1])[None] >= offsets[:, None]
-            y_mix, sc = SSM.ssm_prefill(params["mixer"], cfg, h, valid=valid,
+        with jax.named_scope("ssm"):
+            if decode:
+                y_mix, sc = SSM.ssm_decode(params["mixer"], cfg, h,
+                                           cache["ssm"])
+                new_cache["ssm"] = sc
+            elif prefill:
+                valid = None
+                if offsets is not None:
+                    valid = jnp.arange(x.shape[1])[None] >= offsets[:, None]
+                y_mix, sc = SSM.ssm_prefill(params["mixer"], cfg, h,
+                                            valid=valid,
+                                            use_kernels=use_kernels)
+                old = cache["ssm"]
+                new_cache["ssm"] = {
+                    "h": sc["h"].astype(old["h"].dtype),
+                    "conv": sc["conv"].astype(old["conv"].dtype)}
+            else:
+                y_mix = SSM.ssm_forward(params["mixer"], cfg, h,
                                         use_kernels=use_kernels)
-            old = cache["ssm"]
-            new_cache["ssm"] = {"h": sc["h"].astype(old["h"].dtype),
-                                "conv": sc["conv"].astype(old["conv"].dtype)}
-        else:
-            y_mix = SSM.ssm_forward(params["mixer"], cfg, h,
-                                    use_kernels=use_kernels)
 
     if spec.ff == "dense":
         # Fuse the mixer residual add with the ff pre-norm: one pass over
@@ -210,21 +230,24 @@ def block_apply(params: Params, cfg: ModelConfig, spec: LayerSpec,
                                          use_kernels=use_kernels)
         else:
             h = L.norm_apply(cfg, params["norm2"], x)
-        ax = _tp_axis(params["ff"]["w_gate"].shape[-1], cfg.d_ff)
-        if ax is not None:
-            # Megatron MLP: column-parallel w_gate/w_up, row-parallel w_down.
-            x = x + EP.region_out(
-                L.mlp_apply(params["ff"], EP.region_in(h, ax),
-                            use_kernels=use_kernels), ax)
-        else:
-            x = x + L.mlp_apply(params["ff"], h, use_kernels=use_kernels)
+        with jax.named_scope("mlp"):
+            ax = _tp_axis(params["ff"]["w_gate"].shape[-1], cfg.d_ff)
+            if ax is not None:
+                # Megatron MLP: column-parallel w_gate/w_up, row-parallel
+                # w_down.
+                x = x + EP.region_out(
+                    L.mlp_apply(params["ff"], EP.region_in(h, ax),
+                                use_kernels=use_kernels), ax)
+            else:
+                x = x + L.mlp_apply(params["ff"], h, use_kernels=use_kernels)
     elif spec.ff == "moe":
         if y_mix is not None:
             x = x + y_mix
         h = L.norm_apply(cfg, params["norm2"], x)
-        y, moe_aux = MOE.moe_apply(params["ff"], cfg, h)
-        aux.update(moe_aux)
-        x = x + y
+        with jax.named_scope("moe"):
+            y, moe_aux = MOE.moe_apply(params["ff"], cfg, h)
+            aux.update(moe_aux)
+            x = x + y
     elif y_mix is not None:
         x = x + y_mix
 

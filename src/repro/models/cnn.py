@@ -4,6 +4,13 @@ Zagoruyko 2016), all with (ghost) batch normalization — the models behind
 Table 1 and Figures 1-3.
 
 NHWC layout; BN statistics reduce over (ghost-batch, H, W) per channel.
+
+Named scopes (HLO ``op_name`` metadata, read from a device trace by
+``bench/scopes.py``): ``stage{s}``/``block{b}`` around the residual stages
+and blocks, and one sublayer scope around each piece of work: ``conv``
+(every convolution, the stem and the projection shortcut included),
+``gbn`` (set in :mod:`repro.core.gbn`), ``act`` (the ReLU with the
+residual join or max pool beside it) and ``head`` (pool and classifier).
 """
 from __future__ import annotations
 
@@ -25,16 +32,26 @@ def _conv_init(rng, kh, kw, cin, cout):
     return w
 
 
+@jax.named_scope("conv")
 def _conv(x, w, stride=1):
     return jax.lax.conv_general_dilated(
         x, w, window_strides=(stride, stride), padding="SAME",
         dimension_numbers=("NHWC", "HWIO", "NHWC"))
 
 
-def _avgpool_all(x):
-    return x.mean(axis=(1, 2))
+@jax.named_scope("head")
+def _head(params, x):
+    """Global average pool, then the linear classifier."""
+    return x.mean(axis=(1, 2)) @ params["w"] + params["b"]
 
 
+@jax.named_scope("act")
+def _act(x, skip=None):
+    """ReLU, after the residual join where there is a ``skip``."""
+    return jax.nn.relu(x if skip is None else x + skip)
+
+
+@jax.named_scope("act")
 def _maxpool2(x):
     return jax.lax.reduce_window(x, -jnp.inf, jax.lax.max,
                                  (1, 2, 2, 1), (1, 2, 2, 1), "VALID")
@@ -73,18 +90,17 @@ def convnet_apply(params: Params, state: Params, cfg: VisionModelConfig,
                   use_gbn: Optional[bool] = None,
                   use_kernels: bool = False) -> Tuple[jax.Array, Params]:
     new_state: Params = {"stages": []}
-    for sp, ss in zip(params["stages"], state["stages"]):
-        x = _conv(x, sp["w"])
-        x, ns = norm_apply(cfg, sp["norm"], ss, x, training=training,
-                           ghost_batch_size=ghost_batch_size,
-                           use_gbn=use_gbn, use_kernels=use_kernels)
-        new_state["stages"].append(ns)
-        x = jax.nn.relu(x)
-        if x.shape[1] > 2:
-            x = _maxpool2(x)
-    x = _avgpool_all(x)
-    logits = x @ params["out"]["w"] + params["out"]["b"]
-    return logits, new_state
+    for si, (sp, ss) in enumerate(zip(params["stages"], state["stages"])):
+        with jax.named_scope(f"stage{si}"):
+            x = _conv(x, sp["w"])
+            x, ns = norm_apply(cfg, sp["norm"], ss, x, training=training,
+                               ghost_batch_size=ghost_batch_size,
+                               use_gbn=use_gbn, use_kernels=use_kernels)
+            new_state["stages"].append(ns)
+            x = _act(x)
+            if x.shape[1] > 2:
+                x = _maxpool2(x)
+    return _head(params["out"], x), new_state
 
 
 # ---------------------------------------------------------------------------
@@ -141,28 +157,28 @@ def resnet_apply(params: Params, state: Params, cfg: VisionModelConfig,
     x = _conv(x, params["stem"]["w"])
     x, ns = norm_apply(cfg, params["stem"]["norm"], state["stem"], x, **kw)
     new_state["stem"] = ns
-    x = jax.nn.relu(x)
+    x = _act(x)
     for si, (stage_p, stage_s) in enumerate(zip(params["stages"],
                                                 state["stages"])):
         ns_stage = []
         for bi, (blk, bs) in enumerate(zip(stage_p, stage_s)):
             stride = 2 if (si > 0 and bi == 0) else 1
-            h = _conv(x, blk["w1"], stride=stride)
-            h, n1 = norm_apply(cfg, blk["norm1"], bs["norm1"], h, **kw)
-            h = jax.nn.relu(h)
-            h = _conv(h, blk["w2"])
-            h, n2 = norm_apply(cfg, blk["norm2"], bs["norm2"], h, **kw)
-            skip = x
-            if "proj" in blk:
-                skip = _conv(x, blk["proj"], stride=stride)
-            elif stride != 1:
-                skip = x[:, ::stride, ::stride, :]
-            x = jax.nn.relu(h + skip)
+            with jax.named_scope(f"stage{si}"), \
+                    jax.named_scope(f"block{bi}"):
+                h = _conv(x, blk["w1"], stride=stride)
+                h, n1 = norm_apply(cfg, blk["norm1"], bs["norm1"], h, **kw)
+                h = _act(h)
+                h = _conv(h, blk["w2"])
+                h, n2 = norm_apply(cfg, blk["norm2"], bs["norm2"], h, **kw)
+                skip = x
+                if "proj" in blk:
+                    skip = _conv(x, blk["proj"], stride=stride)
+                elif stride != 1:
+                    skip = x[:, ::stride, ::stride, :]
+                x = _act(h, skip)
             ns_stage.append({"norm1": n1, "norm2": n2})
         new_state["stages"].append(ns_stage)
-    x = _avgpool_all(x)
-    logits = x @ params["out"]["w"] + params["out"]["b"]
-    return logits, new_state
+    return _head(params["out"], x), new_state
 
 
 # ---------------------------------------------------------------------------

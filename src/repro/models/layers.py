@@ -73,12 +73,16 @@ def norm_init(cfg: ModelConfig, d: int, dtype=jnp.float32) -> Params:
     return rmsnorm_init(d, dtype)
 
 
+@jax.named_scope("norm")
 def norm_apply(cfg: ModelConfig, params: Params, x: jax.Array) -> jax.Array:
+    """The pre-sublayer (and final) norm; qk-norm calls
+    :func:`rmsnorm_apply` itself, inside its attention scope."""
     if cfg.norm.kind == "layernorm":
         return layernorm_apply(params, x, cfg.norm.eps)
     return rmsnorm_apply(params, x, cfg.norm.eps)
 
 
+@jax.named_scope("norm")
 def norm_residual_apply(cfg: ModelConfig, params: Params, x: jax.Array,
                         r: jax.Array, *, use_kernels: bool = False
                         ) -> Tuple[jax.Array, jax.Array]:

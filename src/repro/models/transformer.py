@@ -11,6 +11,10 @@ memory) + LM head. Covers all six assigned families:
 through the differentiable Pallas kernels (flash attention with its
 dedicated backward pair, the Mamba chunk scan likewise) — the LM train
 step's hot path under the paper's "train longer" regime.
+
+Besides the blocks' sublayer scopes (:mod:`.blocks`), ``embed`` names the
+embedding lookup, ``ce`` the loss with its LM-head matmul, and ``head`` the
+LM head where the logits are the output (forward, prefill, decode).
 """
 from __future__ import annotations
 
@@ -30,6 +34,16 @@ Params = Dict[str, Any]
 
 def _compute_dtype(cfg: ModelConfig):
     return jnp.dtype(cfg.dtype)
+
+
+@jax.named_scope("embed")
+def _embed(params: Params, cfg: ModelConfig, tokens: jax.Array) -> jax.Array:
+    return params["embed"][tokens].astype(_compute_dtype(cfg))
+
+
+def _lm_head(params: Params, cfg: ModelConfig) -> jax.Array:
+    head = params["embed"] if cfg.tie_embeddings else params["head"]
+    return head.astype(_compute_dtype(cfg))
 
 
 def encoder_config(cfg: ModelConfig) -> ModelConfig:
@@ -115,18 +129,16 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array, *,
             remat: bool = False,
             seq_parallel: bool = False) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """tokens: (B, S) int32 -> (logits (B, S, V), aux losses)."""
-    dtype = _compute_dtype(cfg)
-    Bsz, S = tokens.shape
-    x = params["embed"][tokens].astype(dtype)
-    positions = jnp.broadcast_to(jnp.arange(S)[None], (Bsz, S))
-    x, _, aux = B.stack_apply(params["stack"], cfg, x, positions=positions,
-                              memory=memory, causal=cfg.causal,
-                              use_kernels=use_kernels, remat=remat,
-                              seq_parallel=seq_parallel)
-    x = L.norm_apply(cfg, params["final_norm"], x)
-    head = params["embed"] if cfg.tie_embeddings else params["head"]
-    logits = hints.hint(x @ head.astype(dtype).T, "dp", None, "model")
+    x, aux = hidden_states(params, cfg, tokens, memory=memory,
+                           use_kernels=use_kernels, remat=remat,
+                           seq_parallel=seq_parallel)
+    with jax.named_scope("head"):
+        logits = _logits(params, cfg, x)
     return logits, aux
+
+
+def _logits(params: Params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
+    return hints.hint(x @ _lm_head(params, cfg).T, "dp", None, "model")
 
 
 # ---------------------------------------------------------------------------
@@ -204,14 +216,13 @@ def decode_step(params: Params, cfg: ModelConfig, tokens: jax.Array,
     for ragged (left-padded) prompts: RoPE positions shift to
     ``pos - offsets`` and padded cache slots are masked out of every
     attention."""
-    dtype = _compute_dtype(cfg)
-    x = params["embed"][tokens].astype(dtype)
+    x = _embed(params, cfg, tokens)
     x, new_cache, _ = B.stack_apply(params["stack"], cfg, x, cache=cache,
                                     pos=pos, decode=True,
                                     use_kernels=use_kernels, offsets=offsets)
     x = L.norm_apply(cfg, params["final_norm"], x)
-    head = params["embed"] if cfg.tie_embeddings else params["head"]
-    logits = x @ head.astype(dtype).T
+    with jax.named_scope("head"):
+        logits = x @ _lm_head(params, cfg).T
     return logits, new_cache
 
 
@@ -232,9 +243,8 @@ def prefill_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
     sequence would produce unpadded. The last column is each sequence's
     final prompt token (left padding), so one logits row serves every row.
     """
-    dtype = _compute_dtype(cfg)
     Bsz, P = tokens.shape
-    x = params["embed"][tokens].astype(dtype)
+    x = _embed(params, cfg, tokens)
     base = jnp.broadcast_to(jnp.arange(P)[None], (Bsz, P))
     positions = base if offsets is None else base - offsets[:, None]
     x, new_cache, _ = B.stack_apply(params["stack"], cfg, x, cache=cache,
@@ -242,8 +252,8 @@ def prefill_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
                                     causal=cfg.causal,
                                     use_kernels=use_kernels, offsets=offsets)
     x = L.norm_apply(cfg, params["final_norm"], x[:, -1:])
-    head = params["embed"] if cfg.tie_embeddings else params["head"]
-    logits = x @ head.astype(dtype).T
+    with jax.named_scope("head"):
+        logits = x @ _lm_head(params, cfg).T
     return logits, new_cache
 
 
@@ -257,9 +267,8 @@ def hidden_states(params: Params, cfg: ModelConfig, tokens: jax.Array, *,
                   use_kernels: bool = False, remat: bool = False,
                   seq_parallel: bool = False):
     """Run the stack up to (but excluding) the LM head."""
-    dtype = _compute_dtype(cfg)
     Bsz, S = tokens.shape
-    x = params["embed"][tokens].astype(dtype)
+    x = _embed(params, cfg, tokens)
     positions = jnp.broadcast_to(jnp.arange(S)[None], (Bsz, S))
     x, _, aux = B.stack_apply(params["stack"], cfg, x, positions=positions,
                               memory=memory, causal=cfg.causal,
@@ -332,17 +341,15 @@ def lm_loss(params: Params, cfg: ModelConfig, batch: Dict[str, jax.Array], *,
     memory = get_memory(params, cfg, batch, use_kernels,
                         remat=remat, seq_parallel=seq_parallel)
     targets = tokens[:, 1:]
-    head = params["embed"] if cfg.tie_embeddings else params["head"]
-    if ce_chunk and cfg.padded_vocab % ce_chunk == 0:
-        x, aux = hidden_states(params, cfg, tokens, memory=memory,
-                               use_kernels=use_kernels, remat=remat,
-                               seq_parallel=seq_parallel)
-        ce = _chunked_ce(cfg, x[:, :-1], head, targets, ce_chunk)
-    else:
-        logits, aux = forward(params, cfg, tokens, memory=memory,
-                              use_kernels=use_kernels, remat=remat,
-                              seq_parallel=seq_parallel)
-        ce = _dense_ce(cfg, logits[:, :-1], targets)
+    x, aux = hidden_states(params, cfg, tokens, memory=memory,
+                           use_kernels=use_kernels, remat=remat,
+                           seq_parallel=seq_parallel)
+    with jax.named_scope("ce"):
+        if ce_chunk and cfg.padded_vocab % ce_chunk == 0:
+            head = params["embed"] if cfg.tie_embeddings else params["head"]
+            ce = _chunked_ce(cfg, x[:, :-1], head, targets, ce_chunk)
+        else:
+            ce = _dense_ce(cfg, _logits(params, cfg, x)[:, :-1], targets)
     m = cfg.moe
     total = ce
     if m is not None:

@@ -104,17 +104,6 @@ class Tracer:
             return NULL_SPAN
         return _Span(self, name, args or None)
 
-    def instant(self, name: str, **args) -> None:
-        """A zero-duration marker event (``"ph": "i"``)."""
-        if not self.enabled:
-            return
-        ev = {"name": name, "ph": "i", "s": "t", "pid": self.pid,
-              "tid": threading.get_ident(),
-              "ts": (time.perf_counter_ns() - self.origin_ns) / 1e3}
-        if args:
-            ev["args"] = args
-        self.events.append(ev)
-
     def clear(self) -> None:
         self.events = []
 

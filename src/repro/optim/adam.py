@@ -25,6 +25,7 @@ def init(params: Params) -> AdamState:
                      step=jnp.zeros((), jnp.int32))
 
 
+@jax.named_scope("optimizer")
 def update(grads: Params, state: AdamState, params: Params, *,
            lr: jax.Array, b1: float = 0.9, b2: float = 0.999,
            eps: float = 1e-8, weight_decay: float = 0.0,

@@ -62,6 +62,7 @@ def init(params: Params, momentum_dtype: str = "float32") -> SGDState:
     return SGDState(momentum=mom, step=jnp.zeros((), jnp.int32))
 
 
+@jax.named_scope("optimizer")
 def update(grads: Params, state: SGDState, params: Params, *,
            lr: jax.Array, momentum: float = 0.9, nesterov: bool = False,
            weight_decay: float = 0.0, grad_clip: float = 0.0,

@@ -156,9 +156,10 @@ def make_vision_loss_fn(model_apply: Callable, cfg: VisionModelConfig,
             p, bn_state, cfg, x, training=True,
             ghost_batch_size=lb.ghost_batch_size,
             use_gbn=lb.use_gbn, use_kernels=use_kernels)
-        logp = jax.nn.log_softmax(logits.astype(jnp.float32))
-        nll = -jnp.take_along_axis(logp, y[:, None], axis=1).mean()
-        acc = (logits.argmax(-1) == y).mean()
+        with jax.named_scope("ce"):
+            logp = jax.nn.log_softmax(logits.astype(jnp.float32))
+            nll = -jnp.take_along_axis(logp, y[:, None], axis=1).mean()
+            acc = (logits.argmax(-1) == y).mean()
         return nll, (new_state, acc)
 
     return loss_fn

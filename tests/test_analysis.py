@@ -112,6 +112,25 @@ def test_obs_contract_branch_grammar():
     assert [f.line for f in fs if f.rule == "obs-contract"] == [3]
 
 
+@pytest.mark.tier0
+@pytest.mark.parametrize("call,ok", [
+    ('jax.named_scope("attention")', True),
+    ('jax.named_scope(f"block{i}")', True),
+    ('jax.named_scope(name)', False),
+    ('jax.named_scope("Attention")', False),
+    ('jax.named_scope(f"{i}_block")', False),
+    ('pl.pallas_call(k, name="gbn_bwd_dx")', True),
+    ('pl.pallas_call(k, name="flash_attention_fwd")', True),
+    ('pl.pallas_call(k)', False),
+    ('pl.pallas_call(k, name="gbn")', False),
+    ('pl.pallas_call(k, name=label)', False),
+])
+def test_obs_contract_scope_and_kernel_names(call, ok):
+    src = f"def f(k, i, name, label):\n    return {call}\n"
+    fs = [f for f in lint_source(src, "x.py") if f.rule == "obs-contract"]
+    assert (not fs) is ok, fs
+
+
 # ---------------------------------------------------------------------------
 # tier 0: trace auditor units
 # ---------------------------------------------------------------------------
