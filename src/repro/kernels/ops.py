@@ -387,10 +387,11 @@ def flash_attention_rope(q: jax.Array, k: jax.Array, v: jax.Array,
                          positions: jax.Array, *, theta: float,
                          causal: bool = True,
                          window: Optional[int] = None) -> jax.Array:
-    """Flash attention with RoPE fused into the q/k loads — the model-layout
-    adapter: q (B, T, H, hd); k, v (B, T, KV, hd) UNROTATED; ``positions``
-    broadcastable to (B, T) -> (B, T, H, hd). Replaces the separate
-    ``apply_rope`` passes over q and k in the attention hot path.
+    """Flash attention with RoPE — the model-layout adapter: q (B, T, H,
+    hd); k, v (B, T, KV, hd) UNROTATED; ``positions`` broadcastable to
+    (B, T) -> (B, T, H, hd). The rotation runs once per call, in f32 and
+    in XLA, on the head-major copies made here, and the forward kernel
+    reads the rotated q/k (:func:`flash_attention_rope_pallas`).
 
     Differentiable via ``jax.custom_vjp``
     (:func:`repro.kernels.flash_attention.flash_attention_rope_backward_pallas`),

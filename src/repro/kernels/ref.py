@@ -166,8 +166,8 @@ def rope_ref(x: jax.Array, pos: jax.Array, theta: float) -> jax.Array:
     """Head-major half-rotation RoPE: x (B, H, T, hd), pos (B, T).
 
     Mirrors ``models.layers.apply_rope`` (llama convention:
-    ``freqs_i = theta ** -(i / (hd/2))``) on the kernel layout; the fused
-    attention/decode kernels rotate q/k on load against this."""
+    ``freqs_i = theta ** -(i / (hd/2))``) on the kernel layout; the RoPE
+    attention entry and the decode kernels are checked against it."""
     dt = x.dtype
     hd = x.shape[-1]
     half = hd // 2

@@ -285,8 +285,8 @@ def attention_full(params: Params, cfg: ModelConfig, x: jax.Array,
     B, T, _ = x.shape
     if use_kernels and causal and segment_mask is None:
         from repro.kernels import ops as kops
-        # RoPE rides inside the kernel's q/k loads (no separate apply_rope
-        # pass over the full (B, T, H, hd) tensors)
+        # the kernel entry rotates q/k itself, in f32, with its head-major
+        # copies (no bf16 apply_rope pass here)
         q, k, v = _project_qkv(params, cfg, x, positions, rope=False)
         out = kops.flash_attention_rope(q, k, v, positions,
                                         theta=cfg.rope_theta, causal=True,

@@ -200,14 +200,19 @@ def test_swiglu_grad_vs_oracle(dtype):
 # RoPE-fused flash attention
 # ---------------------------------------------------------------------------
 
+# the qwen3 training cell's head dim and base at the last 148 positions of a
+# 2048-token row, where its rotation angles are largest
+CELL_ANGLES = (1, 2, 1, 148, 128)
+
 ROPE_SHAPES = [
     # (B, H, KV, T, hd) — self-attention: S == T
     (1, 2, 2, 17, 32),
     (2, 4, 2, 64, 64),
+    CELL_ANGLES,
 ]
 
 
-def _rope_inputs(shape, dtype, salt=0):
+def _rope_inputs(shape, dtype, salt=0, first_pos=0):
     B, H, KV, T, hd = shape
     rng = jax.random.PRNGKey((sum(shape) + salt) % 2 ** 31)
     q = jax.random.normal(rng, (B, H, T, hd), jnp.float32).astype(dtype)
@@ -216,8 +221,8 @@ def _rope_inputs(shape, dtype, salt=0):
     v = jax.random.normal(jax.random.fold_in(rng, 2), (B, KV, T, hd),
                           jnp.float32).astype(dtype)
     # staggered per-row positions (continuation offsets, not just 0..T-1)
-    pos = (jnp.arange(T)[None, :] + 3 * jnp.arange(B)[:, None]).astype(
-        jnp.float32)
+    pos = (first_pos + jnp.arange(T)[None, :]
+           + 3 * jnp.arange(B)[:, None]).astype(jnp.float32)
     return q, k, v, pos
 
 
@@ -225,14 +230,23 @@ def _rope_inputs(shape, dtype, salt=0):
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("window", [None, 13])
 def test_flash_attention_rope_vs_ref(shape, dtype, window):
-    """In-kernel q/k rotation == rope-then-attend oracle composition."""
-    q, k, v, pos = _rope_inputs(shape, dtype)
-    out = flash_attention_rope_pallas(q, k, v, pos, theta=1e4, causal=True,
+    """Rotate-then-attend kernel path == rope-then-attend oracle
+    composition, up to the cell's angles (theta 1e6, positions
+    1900-2047)."""
+    theta, first = (1e6, 1900) if shape == CELL_ANGLES else (1e4, 0)
+    q, k, v, pos = _rope_inputs(shape, dtype, first_pos=first)
+    out = flash_attention_rope_pallas(q, k, v, pos, theta=theta, causal=True,
                                       window=window, block_q=32, block_k=32,
                                       interpret=True)
-    want = ref.attention_rope_ref(q, k, v, pos, theta=1e4, causal=True,
+    want = ref.attention_rope_ref(q, k, v, pos, theta=theta, causal=True,
                                   window=window)
     tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    if shape == CELL_ANGLES and dtype == jnp.float32:
+        # an f32 angle near 2047 rad is rounded by up to 1.2e-4 rad, in the
+        # oracle's frequencies as in the kernel path's: each reads up to
+        # 4.1e-5 from a float64 rotation here. Rotated q/k rounded to bf16
+        # would miss by 4.4e-3.
+        tol = 1e-4
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(want, np.float32),
                                rtol=tol, atol=tol)
@@ -288,6 +302,41 @@ def test_flash_attention_rope_grad_vs_oracle(dtype):
         np.testing.assert_allclose(np.asarray(got, np.float32),
                                    np.asarray(want, np.float32),
                                    rtol=tol, atol=tol)
+
+
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs nested in its
+    equations, leaving out the kernels of ``pallas_call``s."""
+    from jax.extend import core as jcore
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for val in eqn.params.values():
+            for sub in val if isinstance(val, (tuple, list)) else (val,):
+                if isinstance(sub, jcore.ClosedJaxpr):
+                    sub = sub.jaxpr
+                if isinstance(sub, jcore.Jaxpr):
+                    yield from _eqns(sub)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flash_attention_rope_forward_is_one_kernel_without_sin_cos(dtype):
+    """One ops.flash_attention_rope forward holds exactly one pallas_call,
+    ``flash_attention_rope_fwd`` (the roofline reader counts it), and the
+    rotation's sin/cos run outside it: none per (q block, kv block) pair."""
+    B, H, KV, T, hd = 1, 2, 1, 40, 32
+    q = jnp.ones((B, T, H, hd), dtype)
+    kv = jnp.ones((B, T, KV, hd), dtype)
+    pos = jnp.arange(T)[None]
+    jaxpr = jax.make_jaxpr(lambda a, b, c: ops.flash_attention_rope(
+        a, b, c, pos, theta=1e6))(q, kv, kv).jaxpr
+    eqns = list(_eqns(jaxpr))
+    calls = [e for e in eqns if e.primitive.name == "pallas_call"]
+    assert [e.params["name"] for e in calls] == ["flash_attention_rope_fwd"]
+    in_kernel = {e.primitive.name for e in _eqns(calls[0].params["jaxpr"])}
+    assert not {"sin", "cos"} & in_kernel
+    assert {"sin", "cos"} <= {e.primitive.name for e in eqns}
 
 
 # ---------------------------------------------------------------------------
